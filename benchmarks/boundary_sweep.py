@@ -19,7 +19,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 import json, sys
 import jax
-from repro import compat
 from repro.configs import INPUT_SHAPES, TrainConfig, get_config
 from repro.core import training
 from repro.launch import inputs as inp
@@ -41,12 +40,12 @@ out = {}
 for b in [int(x) for x in sys.argv[2].split(",")]:
     step = training.make_train_step(cfg, tc, b, remat=True, act_spec=aspec,
                                     moe_groups=16)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         c = jax.jit(step, in_shardings=(pspecs, ospecs, bspecs),
                     out_shardings=(pspecs, ospecs, None),
                     donate_argnums=(0, 1)).lower(aparams, ostate, batch).compile()
     ma = c.memory_analysis()
-    cost = compat.cost_analysis(c)
+    cost = c.cost_analysis()
     coll = rl.collective_bytes(c.as_text())
     out[str(b)] = {
         "temp_gib": ma.temp_size_in_bytes / 2**30,

@@ -47,7 +47,7 @@ S = int(os.environ.get("BENCH_RING_DEVICES", "4"))
 os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={S}"
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax, jax.numpy as jnp
-from repro import compat
+from repro.core.pipeline import make_ring_mesh
 from repro.configs import TrainConfig, get_config
 from repro.core.executor import RingExecutor
 from repro.core.ring import RingTrainer
@@ -59,7 +59,7 @@ from repro.models import params as prm
 M, mb, seq = 4, 1, 32
 cfg = get_config("stablelm-3b").reduced(n_layers=4, repeats=4,
                                         d_model=128, d_ff=256)
-mesh = compat.make_mesh((S,), ("stage",))
+mesh = make_ring_mesh(S)
 tokens = jax.random.randint(jax.random.key(1), (S, M, mb, seq), 0,
                             cfg.vocab_size)
 labels = jax.random.randint(jax.random.key(2), (S, M, mb, seq), 0,
@@ -88,7 +88,7 @@ def time_rounds(step, rounds, reps=3):
     return best
 
 out = {"mesh_devices": S}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     # 1. end-to-end: the paper's schedule walks every boundary; each bump
     #    recompiles S executables on the reference path, 1 on the fused path.
     SCHED_ROUNDS = 8

@@ -52,7 +52,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.core import pipeline as pl
 from repro.core import training
@@ -138,9 +137,8 @@ class _RingBackendBase:
     T = 1                                  # tenants (multi-tenant overrides)
 
     def __init__(self, cfg: ModelConfig, tc: TrainConfig, policy, *,
-                 n_stages: int, params: Optional[Dict[str, Any]] = None,
-                 spans=None, device_profiles=None):
-        from repro.launch.mesh import make_ring_mesh, require_devices
+                 n_stages: int, spans=None, device_profiles=None):
+        from repro.launch.mesh import require_devices
 
         _validate_ring(cfg, n_stages)
         require_devices(n_stages)
@@ -148,8 +146,7 @@ class _RingBackendBase:
         self.S = n_stages
         self.spans = _resolve_ring_spans(cfg, n_stages, spans,
                                          device_profiles)
-        self.mesh = make_ring_mesh(n_stages)
-        self._init_params = params if params is not None else _default_params(cfg, tc)
+        self.mesh = pl.make_ring_mesh(n_stages)
 
     # -- shared surface -------------------------------------------------
     @property
@@ -239,9 +236,11 @@ class ReferenceBackend(_RingBackendBase):
                  spans=None, device_profiles=None):
         from repro.core.ring import RingTrainer
 
-        super().__init__(cfg, tc, policy, n_stages=n_stages, params=params,
-                         spans=spans, device_profiles=device_profiles)
-        self.driver = RingTrainer(cfg, tc, self.mesh, self._init_params,
+        super().__init__(cfg, tc, policy, n_stages=n_stages, spans=spans,
+                         device_profiles=device_profiles)
+        if params is None:
+            params = _default_params(cfg, tc)
+        self.driver = RingTrainer(cfg, tc, self.mesh, params,
                                   n_stages, tc.n_microbatches, schedule=policy,
                                   spans=self.spans)
 
@@ -264,7 +263,7 @@ class ReferenceBackend(_RingBackendBase):
 
     def step(self, batch) -> Dict[str, Any]:
         _, tokens, labels = self._unpack(batch)
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             m = self.driver.round(tokens, labels)
         return {"loss": m["loss"], "boundary": m["boundary"],
                 "depth": self._depth_of(m["boundary"]), "step": m["step"],
@@ -298,10 +297,12 @@ class FusedBackend(_RingBackendBase):
                  device_profiles=None, tenants: int = 1):
         from repro.core.executor import RingExecutor
 
-        super().__init__(cfg, tc, policy, n_stages=n_stages, params=params,
-                         spans=spans, device_profiles=device_profiles)
+        super().__init__(cfg, tc, policy, n_stages=n_stages, spans=spans,
+                         device_profiles=device_profiles)
         self.T = tenants
-        self.driver = RingExecutor(cfg, tc, self.mesh, self._init_params,
+        # params=None: the executor builds the seeded weights directly in
+        # their stage placement — no canonical copy is ever held here.
+        self.driver = RingExecutor(cfg, tc, self.mesh, params,
                                    n_stages, tc.n_microbatches,
                                    cache_capacity=cache_capacity,
                                    schedule=policy, packed=packed,
@@ -323,12 +324,12 @@ class FusedBackend(_RingBackendBase):
 
     def step(self, batch) -> Dict[str, Any]:
         slot, tokens, labels = self._unpack(batch)
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             m = self.driver.round(tokens, labels, slot=slot)
         raw = {"loss": m["loss"], "boundary": m["boundary"],
                "depth": self._depth_of(m["boundary"]), "step": m["step"],
                "tokens": int(tokens.size),
-               "extras": {"losses": m["losses"]}}
+               "extras": {"losses": m["losses"], "mode": m["mode"]}}
         if self.T > 1:
             raw["extras"]["tenant_losses"] = m["tenant_losses"]
         if self.driver.cache is not None:

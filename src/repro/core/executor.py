@@ -85,7 +85,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.core import actcache
 from repro.core import pipeline as pl
@@ -417,7 +416,7 @@ def make_fused_round(cfg: ModelConfig, tc: TrainConfig, mesh: Mesh, *,
         out = (P("stage"), P(), opt_spec, met_spec)
         if mode == "capture":
             out = out + ((P("stage"),) if T == 1 else (P(None, "stage"),))
-        return compat.shard_map(
+        return jax.shard_map(
             fused, mesh=mesh,
             in_specs=(P("stage"), P(), opt_spec, P("stage"), P("stage")),
             out_specs=out)
@@ -462,7 +461,7 @@ def make_fused_round(cfg: ModelConfig, tc: TrainConfig, mesh: Mesh, *,
                                labels)
 
         opt_spec = ring_opt_specs()
-        return compat.shard_map(
+        return jax.shard_map(
             fused_cached_q, mesh=mesh,
             in_specs=(P("stage"), P(), opt_spec, P(None, "stage"),
                       P(None, "stage"), P(), P("stage")),
@@ -476,7 +475,7 @@ def make_fused_round(cfg: ModelConfig, tc: TrainConfig, mesh: Mesh, *,
         return cached_body(stage_blocks, shared, opt_state, h_slot, labels)
 
     opt_spec = ring_opt_specs()
-    return compat.shard_map(
+    return jax.shard_map(
         fused_cached, mesh=mesh,
         in_specs=(P("stage"), P(), opt_spec, P(None, "stage"), P(),
                   P("stage")),
@@ -492,6 +491,14 @@ class RingExecutor:
     optimizer loop, and ``round()`` never blocks on the host (metrics are
     device arrays; see ``materialize_metrics``).
 
+    Weights and Adam moments live where the round reads them: block stacks
+    (and adapter moments) on their stage, shared leaves (and head moments)
+    replicated — written straight into that placement, never staged on one
+    device.  ``params=None`` builds the seeded weights from ``tc.seed`` inside
+    one sharded jit (``pipeline.init_stage_stack``), so no device ever holds
+    the canonical tree; a given canonical tree is stacked into place
+    (``pipeline.place_stage_stack``) and not kept.
+
     With ``cache_capacity > 0``, pass ``slot=<stable batch-slot id>`` to
     ``round``: steady-state revisits of a ``(slot, boundary)`` key skip
     Phase A entirely (see module docstring).  ``slot=None`` (or capacity 0)
@@ -504,7 +511,8 @@ class RingExecutor:
     """
 
     def __init__(self, cfg: ModelConfig, tc: TrainConfig, mesh: Mesh,
-                 params: Dict[str, Any], n_stages: int, n_micro: int, *,
+                 params: Optional[Dict[str, Any]], n_stages: int,
+                 n_micro: int, *,
                  donate: bool = True, cache_capacity: int = 0,
                  schedule: Optional[Any] = None, packed: bool = True,
                  cache_dtype: str = "native",
@@ -525,8 +533,12 @@ class RingExecutor:
         # that reason in blocks-per-stage); ragged layouts use self.spans.
         self.lps = (cfg.repeats // n_stages
                     if not pl.is_ragged(self.spans) else None)
-        self.stage_blocks, self.shared = pl.stage_stack(params, cfg, n_stages,
-                                                        spans=self.spans)
+        if params is None:
+            self.stage_blocks, self.shared = pl.init_stage_stack(
+                cfg, mesh, jax.random.key(tc.seed), spans=self.spans)
+        else:
+            self.stage_blocks, self.shared = pl.place_stage_stack(
+                params, cfg, mesh, spans=self.spans)
         if tenants > 1:
             # One frozen trunk, T adapter sets: adapters gain an interior
             # tenant axis [S, T, max_span, ...] (stage axis stays leading so
@@ -540,9 +552,10 @@ class RingExecutor:
             self.shared = {
                 **self.shared,
                 "head": adamw.tenant_stack(self.shared["head"], tenants)}
-        self._params_rest = {k: v for k, v in params.items()
-                             if k not in ("blocks",)}
-        self.opt_state = ring_opt_init(self.stage_blocks, self.shared)
+        opt_sharding = jax.tree.map(lambda spec: NamedSharding(mesh, spec),
+                                    ring_opt_specs())
+        self.opt_state = jax.jit(ring_opt_init, out_shardings=opt_sharding)(
+            self.stage_blocks, self.shared)
         # per-tenant cache accounting (satellite of the partitioned cache:
         # a tenant's invalidation must not move its neighbors' hit-rates)
         self.tenant_hits = [0] * tenants
@@ -698,6 +711,7 @@ class RingExecutor:
         self._last_boundary = boundary
 
         cache_hit = False
+        mode = "direct"
         tenant_losses = None
         use_cache = self.cache is not None and slot is not None
         if use_cache:
@@ -730,7 +744,9 @@ class RingExecutor:
                         self.stage_blocks, self.shared, self.opt_state,
                         self.cache.buffer, row_arg, labels)
                 cache_hit = True
+                mode = "cached"
             else:
+                mode = "capture"
                 fn = self._fn(boundary, "capture")
                 (self.stage_blocks, self.shared, self.opt_state,
                  mets, h_cap) = fn(
@@ -758,7 +774,7 @@ class RingExecutor:
         self.step += self.S
         out = {"loss": mean_loss, "losses": losses,
                "boundary": boundary, "step": self.step,
-               "cache_hit": cache_hit}
+               "cache_hit": cache_hit, "mode": mode}
         if tenant_losses is not None:
             out["tenant_losses"] = tenant_losses
             out["tenant_cache_hits"] = list(self.tenant_hits)
@@ -794,10 +810,8 @@ class RingExecutor:
             self.spans = new
             self.lps = (self.cfg.repeats // self.S
                         if not pl.is_ragged(new) else None)
-            self.stage_blocks, self.shared = pl.stage_stack(
-                params, self.cfg, self.S, spans=new)
-            self._params_rest = {k: v for k, v in params.items()
-                                 if k != "blocks"}
+            self.stage_blocks, self.shared = pl.place_stage_stack(
+                params, self.cfg, self.mesh, spans=new)
             self.opt_state = {
                 **self.opt_state,
                 "m": {**self.opt_state["m"],
@@ -884,7 +898,7 @@ class RingExecutor:
         count = np.asarray(self.opt_state["count"])
 
         self.S = new_S
-        self.mesh = compat.make_mesh((new_S,), ("stage",))
+        self.mesh = pl.make_ring_mesh(new_S)
         self.spans = new_spans
         self.lps = (self.cfg.repeats // new_S
                     if not pl.is_ragged(new_spans) else None)
@@ -977,20 +991,20 @@ class RingExecutor:
         """
         if self.T == 1:
             assert tenant in (None, 0), tenant
-            return pl.unstack(self.stage_blocks, self.cfg, self._params_rest,
-                              self.shared, spans=self.spans)
+            entry = pl.unstack_entry(self.stage_blocks, self.spans)
+            return {**self.shared, "blocks": (entry,)}
         bb_flat = self._unstack_backbone(self.spans)
         ad_flat = self._unstack_adapters(self.stage_blocks["adapter"],
                                          self.spans)
         if tenant is None:
             entry = {**bb_flat, "adapter": ad_flat}
-            return {**self._params_rest, **self.shared, "blocks": (entry,)}
+            return {**self.shared, "blocks": (entry,)}
         entry = {**bb_flat,
                  "adapter": jax.tree.map(lambda x: x[tenant], ad_flat)}
         shared = {**self.shared,
                   "head": jax.tree.map(lambda x: x[tenant],
                                        self.shared["head"])}
-        return {**self._params_rest, **shared, "blocks": (entry,)}
+        return {**shared, "blocks": (entry,)}
 
     # ------------------------------------------------------------------
     def export_adapters(self, tenant: int = 0) -> Dict[str, Any]:
@@ -1074,10 +1088,8 @@ class RingExecutor:
         """Install a canonical tree from ``export_params()`` (T=1 single-model
         or T>1 tenant-stacked) back into the live stage layout."""
         if self.T == 1:
-            self.stage_blocks, self.shared = pl.stage_stack(
-                params, self.cfg, self.S, spans=self.spans)
-            self._params_rest = {k: v for k, v in params.items()
-                                 if k != "blocks"}
+            self.stage_blocks, self.shared = pl.place_stage_stack(
+                params, self.cfg, self.mesh, spans=self.spans)
             return
         entry = params["blocks"][0]
         bb_flat = {k: v for k, v in entry.items() if k != "adapter"}
@@ -1085,5 +1097,3 @@ class RingExecutor:
             **pl.stack_entry(bb_flat, self.spans),
             "adapter": self._stack_adapters(entry["adapter"], self.spans)}
         self.shared = {k: params[k] for k in self.shared}
-        self._params_rest = {k: v for k, v in params.items()
-                             if k != "blocks"}

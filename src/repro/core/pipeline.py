@@ -102,12 +102,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.core.partition import (Span, frozen_stage_count, normalize_spans,
                                   span_sizes, uniform_assignment)
+from repro.models import params as prm
 from repro.models import transformer as tfm
 from repro.models.blocks import BlockCtx, apply_block
 
@@ -220,6 +220,48 @@ def stage_stack(params: Dict[str, Any], cfg: ModelConfig, n_stages: int, *,
     stage_blocks = stack_entry(params["blocks"][0], spans)
     shared = {k: v for k, v in params.items() if k != "blocks"}
     return stage_blocks, shared
+
+
+def make_ring_mesh(n_stages: int, *,
+                   devices: Optional[Sequence] = None) -> Mesh:
+    """Ring-pipeline mesh over the 'stage' axis: one device per stage, taken
+    from ``devices`` (default: the backend's devices, first ``n_stages``).
+    Auto axis type (``jax.make_mesh`` defaults to Explicit): the ring places
+    its arrays with ``NamedSharding`` / ``shard_map`` itself."""
+    return jax.make_mesh((n_stages,), ("stage",),
+                         axis_types=(AxisType.Auto,), devices=devices)
+
+
+def stage_shardings(mesh: Mesh) -> Tuple[NamedSharding, NamedSharding]:
+    """Where ``stage_stack``'s two outputs live: the block stacks split over
+    'stage' (each device holds its own span), the shared leaves replicated."""
+    return NamedSharding(mesh, P("stage")), NamedSharding(mesh, P())
+
+
+def place_stage_stack(params: Dict[str, Any], cfg: ModelConfig, mesh: Mesh, *,
+                      spans: Optional[Sequence[Span]] = None
+                      ) -> Tuple[Any, Dict[str, Any]]:
+    """``stage_stack`` of a canonical tree, written straight into
+    ``stage_shardings(mesh)`` (no stacked copy on the default device)."""
+    S = mesh.shape["stage"]
+    return jax.jit(lambda p: stage_stack(p, cfg, S, spans=spans),
+                   out_shardings=stage_shardings(mesh))(params)
+
+
+def init_stage_stack(cfg: ModelConfig, mesh: Mesh, key: Array, *,
+                     spans: Optional[Sequence[Span]] = None
+                     ) -> Tuple[Any, Dict[str, Any]]:
+    """Seeded weights built directly where they live: one jit materializes
+    and stacks them with ``stage_shardings(mesh)`` as its output placement,
+    so the canonical tree exists only inside the program and XLA generates
+    each stage's blocks on that stage's device.  Bit-identical to
+    ``stage_stack(prm.materialize(param_defs(cfg), key, cfg.dtype), ...)``."""
+    S = mesh.shape["stage"]
+    defs = prm.param_defs(cfg)
+    return jax.jit(
+        lambda k: stage_stack(prm.materialize(defs, k, cfg.dtype), cfg, S,
+                              spans=spans),
+        out_shardings=stage_shardings(mesh))(key)
 
 
 def unstack(stage_blocks, cfg: ModelConfig, params: Dict[str, Any],
@@ -369,9 +411,9 @@ def make_ring_round(cfg: ModelConfig, mesh: Mesh, *, n_stages: int, owner: int,
         loss = jnp.mean(lse - gold) * is_owner
         return lax.psum(loss, "stage")
 
-    return compat.shard_map(round_fn, mesh=mesh,
-                            in_specs=(P("stage"), P(), P("stage"), P("stage")),
-                            out_specs=P())
+    return jax.shard_map(round_fn, mesh=mesh,
+                         in_specs=(P("stage"), P(), P("stage"), P("stage")),
+                         out_specs=P())
 
 
 def make_ring_train_round(cfg: ModelConfig, mesh: Mesh, *, n_stages: int,

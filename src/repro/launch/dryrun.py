@@ -24,7 +24,6 @@ from typing import Any, Dict
 
 import jax
 
-from repro import compat
 from repro import roofline as rl
 from repro.configs import INPUT_SHAPES, ASSIGNED, TrainConfig, get_config, shape_runnable
 from repro.core import training
@@ -55,7 +54,7 @@ def lower_combo(arch: str, shape_name: str, *, multi_pod: bool,
     tc = TrainConfig()
 
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             batch, bspecs = inp.train_inputs(cfg, shape, mesh)
             ospecs = inp.opt_state_specs(cfg, mesh)
@@ -97,7 +96,7 @@ def lower_combo(arch: str, shape_name: str, *, multi_pod: bool,
         t_compile = time.time() - t0
 
     ma = compiled.memory_analysis()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = rl.collective_bytes(hlo)
     mf = rl.model_flops(cfg, shape)

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import params as prm
 from repro.models import transformer as tfm
 
@@ -167,6 +168,7 @@ class BatchServer:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
     ap.add_argument("--reduced", action="store_true", default=True)

@@ -32,6 +32,7 @@ from typing import Any, Dict, Optional
 from repro.api import (ExplicitPolicy, LoggingCallback, RingSession,
                        resolve_policy)
 from repro.configs import TrainConfig, get_config
+from repro.launch.compile_cache import use_compile_cache
 
 
 def train_pjit(cfg, tc: TrainConfig, *, steps: int, log_every: int = 10,
@@ -182,6 +183,7 @@ def train_ring(cfg, tc: TrainConfig, *, rounds: int, n_stages: int,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mbert-squad")
     ap.add_argument("--mode", choices=["pjit", "ring"], default="pjit")

@@ -16,6 +16,7 @@ so the transformer scans over repeats (outer) and count (inner) with compact HLO
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
@@ -290,11 +291,23 @@ def _init_leaf(pd: PD, key: jax.Array, dtype: jnp.dtype) -> jax.Array:
 
 
 def materialize(defs: Any, key: jax.Array, dtype: str = "bfloat16") -> Any:
+    """Seeded initial values for ``defs``, computed as ONE jitted program.
+
+    Eager and jitted initializers round some leaves differently, so every
+    caller goes through the same program: a caller that traces this inside
+    its own jit (``pipeline.init_stage_stack`` builds the stage-stacked
+    weights directly in their sharding) gets bit-identical values."""
     leaves, treedef = jax.tree.flatten(defs, is_leaf=_IS_PD)
-    keys = jax.random.split(key, len(leaves))
+    return jax.tree.unflatten(treedef,
+                              _init_leaves(tuple(leaves), key,
+                                           jnp.dtype(dtype).name))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2))
+def _init_leaves(pds: Tuple[PD, ...], key: jax.Array, dtype: str) -> list:
+    keys = jax.random.split(key, len(pds))
     dt = jnp.dtype(dtype)
-    out = [_init_leaf(pd, k, dt) for pd, k in zip(leaves, keys)]
-    return jax.tree.unflatten(treedef, out)
+    return [_init_leaf(pd, k, dt) for pd, k in zip(pds, keys)]
 
 
 def abstract(defs: Any, dtype: str = "bfloat16") -> Any:

@@ -232,7 +232,7 @@ def test_cache_shape_mismatch_bypasses_at_nonuniform_boundary():
 PRELUDE = """
 import json
 import jax, jax.numpy as jnp
-from repro import compat
+from repro.core.pipeline import make_ring_mesh
 from repro.configs import TrainConfig, get_config
 from repro.models import params as P
 from repro.core.executor import RingExecutor
@@ -248,7 +248,7 @@ def fresh_params():
                                           jnp.float32).astype(ad["w_up"].dtype)
     return params
 
-mesh = compat.make_mesh((4,), ("stage",))
+mesh = make_ring_mesh(4)
 
 def slot_batch(k, seq_=seq):
     t = jax.random.randint(jax.random.key(10 + k), (S, M, mb, seq_), 0,
@@ -274,7 +274,7 @@ tc = TrainConfig(learning_rate=1e-3, unfreeze_interval=4 * S, n_microbatches=M,
                  batch_size=mb, seq_len=seq)
 batches = [slot_batch(0), slot_batch(1)]
 out = {"plain_loss": [], "cached_loss": [], "hit": [], "b": []}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     plain = RingExecutor(cfg, tc, mesh, fresh_params(), S, M)
     drv = RingExecutor(cfg, tc, mesh, fresh_params(), S, M, cache_capacity=2)
     for r in range(12):
@@ -321,7 +321,7 @@ tc = TrainConfig(learning_rate=1e-3, unfreeze_interval=10**6, n_microbatches=M,
 b0, b1 = slot_batch(0), slot_batch(1)
 short = slot_batch(2, seq_=16)
 out = {}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     drv = RingExecutor(cfg, tc, mesh, fresh_params(), S, M, cache_capacity=1)
     drv.round(*b0, slot=None)                 # streaming round: direct path
     out["after_none"] = drv.cache.stats()

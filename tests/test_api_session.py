@@ -282,7 +282,7 @@ def test_pjit_session_matches_staged_recompile_oracle():
 PRELUDE = """
 import json
 import jax, jax.numpy as jnp
-from repro import compat
+from repro.core.pipeline import make_ring_mesh
 from repro.api import RingSession
 from repro.configs import TrainConfig, get_config
 from repro.models import params as P
@@ -319,12 +319,12 @@ def test_ring_backends_match_direct_drivers():
 from repro.core.ring import RingTrainer
 from repro.core.executor import RingExecutor
 
-mesh = compat.make_mesh((4,), ("stage",))
+mesh = make_ring_mesh(4)
 tc = TrainConfig(learning_rate=1e-3, unfreeze_interval=S, n_microbatches=M,
                  batch_size=mb, seq_len=seq)
 tokens, labels = slot_batch(0)
 out = {k: [] for k in ("drv_ref", "ses_ref", "drv_fused", "ses_fused", "b")}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     drv_ref = RingTrainer(cfg, tc, mesh, fresh_params(), S, M)
     drv_fused = RingExecutor(cfg, tc, mesh, fresh_params(), S, M)
     ses_ref = RingSession.create(cfg, tc, backend="reference", n_stages=S,

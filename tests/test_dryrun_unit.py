@@ -24,14 +24,15 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
 import json, dataclasses
 import jax
-from repro import compat
+from jax.sharding import AxisType
 from repro.configs import TrainConfig, get_config
 from repro.core import training
 from repro.launch import inputs as inp
 from repro import sharding as sh
 from repro.models import params as prm
 
-mesh = compat.make_mesh((4, 4), ("data", "model"))
+mesh = jax.make_mesh((4, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 out = {}
 for arch in ["stablelm-3b", "olmoe-1b-7b", "rwkv6-7b"]:
     cfg = get_config(arch).reduced(d_model=256, n_heads=4, n_kv_heads=4)
@@ -46,11 +47,11 @@ for arch in ["stablelm-3b", "olmoe-1b-7b", "rwkv6-7b"]:
     bspecs = {"tokens": P("data"), "labels": P("data")}
     step = training.make_train_step(cfg, TrainConfig(), 1, remat=True)
     ostate = inp.abstract_opt_state(cfg)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         c = jax.jit(step).lower(aparams, ostate, batch).compile()
     ma = c.memory_analysis()
     out[arch] = {"temp": ma.temp_size_in_bytes,
-                 "flops": compat.cost_analysis(c).get("flops", 0.0)}
+                 "flops": c.cost_analysis().get("flops", 0.0)}
 print(json.dumps(out))
 """
     env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"),

@@ -247,7 +247,7 @@ PRELUDE = """
 import json
 import numpy as np
 import jax, jax.numpy as jnp
-from repro import compat
+from repro.core.pipeline import make_ring_mesh
 from repro.configs import TrainConfig, get_config
 from repro.models import params as P
 from repro.core import pipeline as pl
@@ -267,7 +267,7 @@ def fresh_params():
                                           jnp.float32).astype(ad["w_up"].dtype)
     return params
 
-mesh = compat.make_mesh((S,), ("stage",))
+mesh = make_ring_mesh(S)
 
 def batch(k=0):
     t = jax.random.randint(jax.random.key(10 + k), (S, M, mb, seq), 0,
@@ -311,7 +311,7 @@ for name, layout, dead in cases:
     profs = parse_device_profiles(SPEEDS)
     drv = RingExecutor(cfg, tc, mesh, fresh_params(), S, M, spans=layout,
                        cache_capacity=2)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for r in range(4):
             t, l = batches[r % 2]
             RingExecutor.materialize_metrics(drv.round(t, l, slot=r % 2))
@@ -337,7 +337,7 @@ for name, layout, dead in cases:
 
     rows = np.asarray([i for i in range(S) if i != dead])
     losses, hits = [], []
-    with compat.set_mesh(drv.mesh):
+    with jax.set_mesh(drv.mesh):
         for r in range(4):
             t, l = batches[r % 2]
             ma = RingExecutor.materialize_metrics(

@@ -34,7 +34,7 @@ def _run_sub(code: str) -> dict:
 PRELUDE = """
 import json
 import jax, jax.numpy as jnp
-from repro import compat
+from repro.core.pipeline import make_ring_mesh
 from repro.configs import TrainConfig, get_config
 from repro.models import params as P
 from repro.core.ring import RingTrainer
@@ -51,7 +51,7 @@ def fresh_params():
                                           jnp.float32).astype(ad["w_up"].dtype)
     return params
 
-mesh = compat.make_mesh((4,), ("stage",))
+mesh = make_ring_mesh(4)
 tokens = jax.random.randint(jax.random.key(1), (S, M, mb, seq), 0, cfg.vocab_size)
 labels = jax.random.randint(jax.random.key(2), (S, M, mb, seq), 0, cfg.vocab_size)
 f32 = lambda x: x.astype(jnp.float32)
@@ -67,7 +67,7 @@ def test_fused_matches_reference_across_boundary_bump():
 tc = TrainConfig(learning_rate=1e-3, unfreeze_interval=S, n_microbatches=M,
                  batch_size=mb, seq_len=seq)
 out = {"ref_loss": [], "fused_loss": [], "ref_b": [], "fused_b": []}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     ref = RingTrainer(cfg, tc, mesh, fresh_params(), S, M)
     ex = RingExecutor(cfg, tc, mesh, fresh_params(), S, M)
     for r in range(3):
@@ -105,7 +105,7 @@ def test_frozen_stages_and_moments_untouched():
 import numpy as np
 tc = TrainConfig(learning_rate=1e-3, unfreeze_interval=10**6, n_microbatches=M,
                  batch_size=mb, seq_len=seq)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     ex = RingExecutor(cfg, tc, mesh, fresh_params(), S, M, donate=False)
     ad0 = jax.tree.map(jnp.copy, ex.stage_blocks["adapter"])
     F = ex.boundary_at(0)    # == 3 (initial depth 1, 1 repeat per stage)
@@ -133,3 +133,56 @@ with compat.set_mesh(mesh):
     assert res["hot_m_nonzero"]
     # same boundary both rounds: still exactly one compilation
     assert res["traces"] == {"3": 1}
+
+
+def test_seeded_stage_stack_matches_canonical_and_reference_session():
+    """``pipeline.init_stage_stack`` (the ``params=None`` path every fused
+    session takes) builds bit-for-bit ``stage_stack(materialize(...))``,
+    already in ``stage_shardings(mesh)`` with one span per device; a
+    default-params fused session starts from exactly the reference session's
+    weights (built through the canonical tree) and tracks it over one round
+    within the cross-driver pins of the first test."""
+    code = PRELUDE + """
+import numpy as np
+from repro.api import RingSession
+from repro.core import pipeline as pl
+
+tc = TrainConfig(learning_rate=1e-3, unfreeze_interval=S, n_microbatches=M,
+                 batch_size=mb, seq_len=seq)
+key = jax.random.key(tc.seed)
+same_bits = lambda a, b: all(
+    x.dtype == y.dtype and x.shape == y.shape and x.tobytes() == y.tobytes()
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+with jax.set_mesh(mesh):
+    sb, sh = pl.init_stage_stack(cfg, mesh, key)
+    want = jax.device_get(pl.stage_stack(
+        P.materialize(P.param_defs(cfg), key, cfg.dtype), cfg, S))
+    blk_sh, rep_sh = pl.stage_shardings(mesh)
+    out = {
+        "bit_equal": same_bits(jax.device_get((sb, sh)), want),
+        "blocks_placed": all(
+            x.sharding.is_equivalent_to(blk_sh, x.ndim)
+            and all(s.data.shape[0] == 1 for s in x.addressable_shards)
+            for x in jax.tree.leaves(sb)),
+        "shared_placed": all(x.sharding.is_equivalent_to(rep_sh, x.ndim)
+                             for x in jax.tree.leaves(sh)),
+    }
+    ses_ref = RingSession.create(cfg, tc, backend="reference", n_stages=S)
+    ses_fused = RingSession.create(cfg, tc, backend="fused", n_stages=S)
+    out["init_equal"] = same_bits(
+        jax.device_get(ses_ref.backend.export_params()),
+        jax.device_get(ses_fused.backend.export_params()))
+    sr = ses_ref.step((tokens, labels)).materialize()
+    sf = ses_fused.step((tokens, labels)).materialize()
+    out["losses"] = [sr.loss, sf.loss]
+    out["param_err"] = maxerr(ses_ref.backend.export_params(),
+                              ses_fused.backend.export_params())
+print(json.dumps(out))
+"""
+    res = _run_sub(code)
+    assert res["bit_equal"], "seeded stage stack differs from the canonical one"
+    assert res["blocks_placed"] and res["shared_placed"]
+    assert res["init_equal"], "fused and reference sessions start apart"
+    ref_loss, fused_loss = res["losses"]
+    assert abs(ref_loss - fused_loss) < 2e-2, res["losses"]
+    assert res["param_err"] < 5e-2

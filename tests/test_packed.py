@@ -39,7 +39,7 @@ def _run_sub(code: str) -> dict:
 PRELUDE = """
 import json
 import jax, jax.numpy as jnp
-from repro import compat
+from repro.core.pipeline import make_ring_mesh
 from repro.configs import TrainConfig, get_config
 from repro.models import params as P
 from repro.core.executor import RingExecutor
@@ -78,11 +78,10 @@ for S, M, lps in ((4, 3, 1), (2, 2, 2), (4, 1, 1)):
     mb, seq = 1, 32
     tc = TrainConfig(learning_rate=1e-3, unfreeze_interval=S, n_microbatches=M,
                      batch_size=mb, seq_len=seq)
-    mesh = compat.make_mesh((S,), ("stage",),
-                            devices=jax.devices()[:S])
+    mesh = make_ring_mesh(S, devices=jax.devices()[:S])
     tokens, labels = batch(cfg, S, M, mb, seq)
     rec = {"scan_loss": [], "packed_loss": [], "b": []}
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         scan = RingExecutor(cfg, tc, mesh, fresh_params(cfg), S, M,
                             packed=False)
         pk = RingExecutor(cfg, tc, mesh, fresh_params(cfg), S, M, packed=True)
@@ -124,10 +123,10 @@ cfg = get_config("stablelm-3b").reduced(n_layers=4, repeats=4,
                                         d_model=128, d_ff=256)
 tc = TrainConfig(learning_rate=1e-3, unfreeze_interval=4 * S, n_microbatches=M,
                  batch_size=mb, seq_len=seq)
-mesh = compat.make_mesh((4,), ("stage",))
+mesh = make_ring_mesh(4)
 batches = [batch(cfg, S, M, mb, seq, k=0), batch(cfg, S, M, mb, seq, k=1)]
 out = {}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     plain = RingExecutor(cfg, tc, mesh, fresh_params(cfg), S, M, packed=False)
     plain_loss = []
     for r in range(8):

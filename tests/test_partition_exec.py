@@ -217,7 +217,7 @@ def test_span_tick_counts_equal_lps_form_when_uniform():
 PRELUDE = """
 import json
 import jax, jax.numpy as jnp
-from repro import compat
+from repro.core.pipeline import make_ring_mesh
 from repro.configs import TrainConfig, get_config
 from repro.models import params as P
 from repro.core.executor import RingExecutor
@@ -236,7 +236,7 @@ def fresh_params():
                                           jnp.float32).astype(ad["w_up"].dtype)
     return params
 
-mesh = compat.make_mesh((S,), ("stage",))
+mesh = make_ring_mesh(S)
 
 def batch(k=0):
     t = jax.random.randint(jax.random.key(10 + k), (S, M, mb, seq), 0,
@@ -268,7 +268,7 @@ tc = TrainConfig(learning_rate=1e-3, unfreeze_interval=10**6,
                  batch_size=mb, seq_len=seq)
 batches = [batch(0), batch(1)]
 out = {}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     oracle = RingExecutor(cfg, tc, mesh, fresh_params(), S, M)  # 4:4:3:3
     o_losses = []
     for r in range(4):
@@ -342,7 +342,7 @@ tc = TrainConfig(learning_rate=1e-3, unfreeze_interval=2 * S,
 spans = [4, 5, 2, 3]
 tokens, labels = batch(0)
 out = {"fused": [], "ref": [], "b": []}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     fused = RingExecutor(cfg, tc, mesh, fresh_params(), S, M, spans=spans)
     ref = RingTrainer(cfg, tc, mesh, fresh_params(), S, M, spans=spans)
     for r in range(6):
@@ -376,7 +376,7 @@ tc = TrainConfig(learning_rate=1e-3, unfreeze_interval=10**6,
                  batch_size=mb, seq_len=seq)
 batches = [batch(0), batch(1)]
 out = {"plain": [], "repart": [], "hits": []}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     plain = RingExecutor(cfg, tc, mesh, fresh_params(), S, M)
     drv = RingExecutor(cfg, tc, mesh, fresh_params(), S, M, cache_capacity=2)
     for r in range(8):

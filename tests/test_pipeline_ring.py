@@ -30,7 +30,7 @@ def _run_sub(code: str) -> dict:
 PRELUDE = """
 import json
 import jax, jax.numpy as jnp
-from repro import compat
+from repro.core.pipeline import make_ring_mesh
 from repro.configs import get_config
 from repro.models import params as P, transformer as T
 from repro.core import pipeline as pl, training
@@ -41,7 +41,7 @@ params = P.materialize(P.param_defs(cfg), jax.random.key(0))
 ad = params["blocks"][0]["adapter"]
 ad["w_up"] = 0.02 * jax.random.normal(jax.random.key(9), ad["w_up"].shape,
                                       jnp.float32).astype(ad["w_up"].dtype)
-mesh = compat.make_mesh((4,), ("stage",))
+mesh = make_ring_mesh(4)
 S, M, mb, seq = 4, 3, 2, 32
 tokens = jax.random.randint(jax.random.key(1), (S, M, mb, seq), 0, cfg.vocab_size)
 labels = jax.random.randint(jax.random.key(2), (S, M, mb, seq), 0, cfg.vocab_size)
@@ -53,7 +53,7 @@ stage_blocks, shared = pl.stage_stack(params, cfg, S)
 def test_ring_loss_matches_reference_all_owners():
     code = PRELUDE + """
 res = {}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     for owner in range(4):
         fn = jax.jit(pl.make_ring_round(cfg, mesh, n_stages=S, owner=owner,
                                         boundary=0, n_micro=M))
@@ -74,7 +74,7 @@ print(json.dumps(res))
 def test_ring_grads_match_pjit_path():
     code = PRELUDE + """
 owner, boundary = 1, 2
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     fn = jax.jit(pl.make_ring_train_round(cfg, mesh, n_stages=S, owner=owner,
                                           boundary=boundary, n_micro=M))
     loss, (gad, ghead) = fn(stage_blocks, shared, tokens, labels)
@@ -115,7 +115,7 @@ clients = make_client_datasets(S, vocab=cfg.vocab_size, n_per_client=32,
                                seq=seq, seed=0)
 rb = RingBatcher(clients, M, mb, seed=0)
 losses = []
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     for r in range(6):
         tk, lb = rb.next()
         m = trainer.round(tk, lb)
@@ -147,14 +147,14 @@ def global_local_round(owner, stage_blocks, shared, tokens, labels):
         emb_g = pl.gather_embeddings(cfg, shared_rest, my_tokens, pos)
         l_loc = local(owner, my_blocks, shared, emb_g, labels[0])
         return jax.lax.psum(l_loc, "stage")
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(Pspec(), Pspec("stage"), Pspec(), Pspec("stage"),
                   Pspec("stage")),
         out_specs=Pspec())(owner, stage_blocks, shared, tokens, labels)
 
 res = {}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     fused = jax.jit(global_local_round)
     for owner in range(4):
         ref_fn = jax.jit(pl.make_ring_round(cfg, mesh, n_stages=S, owner=owner,
