@@ -19,6 +19,9 @@ import dataclasses
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
+import jax
+
+from repro import scopes
 from repro.core.executor import scalarize as _scalarize
 
 
@@ -54,9 +57,11 @@ class RoundMetrics:                        # live instances in a WeakSet
                 wall_s=self.wall_s if wall_s is None else wall_s,
                 tokens_per_sec=(self.tokens_per_sec if tokens_per_sec is None
                                 else tokens_per_sec))
+        with jax.profiler.TraceAnnotation(scopes.SYNC):
+            loss = _scalarize(self.loss)
+            extras = {k: _scalarize(v) for k, v in self.extras.items()}
         return dataclasses.replace(
-            self, loss=_scalarize(self.loss),
-            extras={k: _scalarize(v) for k, v in self.extras.items()},
+            self, loss=loss, extras=extras,
             wall_s=self.wall_s if wall_s is None else wall_s,
             tokens_per_sec=(self.tokens_per_sec if tokens_per_sec is None
                             else tokens_per_sec),
@@ -71,8 +76,10 @@ class RoundMetrics:                        # live instances in a WeakSet
         would be garbage.  Idempotent; timing fields are left for the run
         loop's flush to fill."""
         if not self.materialized:
-            self.loss = _scalarize(self.loss)
-            self.extras = {k: _scalarize(v) for k, v in self.extras.items()}
+            with jax.profiler.TraceAnnotation(scopes.SYNC):
+                self.loss = _scalarize(self.loss)
+                self.extras = {k: _scalarize(v)
+                               for k, v in self.extras.items()}
             self.materialized = True
         return self
 

@@ -41,6 +41,9 @@ import warnings
 import weakref
 from typing import Any, Dict, List, Optional, Sequence
 
+import jax
+
+from repro import scopes
 from repro.checkpoint import checkpoint as ckpt
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.core.elastic import parse_chaos_events
@@ -217,10 +220,21 @@ class RingSession:
     def step(self, batch: Any = None) -> RoundMetrics:
         """One backend step (a full ring round for ring backends, one
         optimizer step for pjit).  Returns possibly-device metrics; call
-        ``.materialize()`` (or use :meth:`run`) to host-sync them."""
+        ``.materialize()`` (or use :meth:`run`) to host-sync them.
+
+        Under ``jax.profiler.trace`` the call records a ``ringada.round``
+        span holding ``ringada.data`` (drawing the batch) and then
+        ``ringada.dispatch`` (the backend step), see :mod:`repro.scopes`."""
+        with jax.profiler.StepTraceAnnotation(scopes.ROUND,
+                                              step_num=self.step_count):
+            return self._step(batch)
+
+    def _step(self, batch: Any) -> RoundMetrics:
         if batch is None:
-            batch = self.data.next()
-        raw = self.backend.step(batch)
+            with jax.profiler.TraceAnnotation(scopes.DATA):
+                batch = self.data.next()
+        with jax.profiler.TraceAnnotation(scopes.DISPATCH):
+            raw = self.backend.step(batch)
         if raw.get("layout_changed"):
             # an elastic shrink/grow/repartition happened INSIDE the step:
             # span edges (and so boundary alignment granularity) moved, so
@@ -296,12 +310,12 @@ class RingSession:
             cb.on_start(self)
         history: List[Dict[str, Any]] = []
         pending: List[RoundMetrics] = []
-        t0 = last_t = time.time()
+        t0 = last_t = time.perf_counter()
         tokens_acc = 0
 
         def flush():
             nonlocal last_t, tokens_acc
-            now = time.time()
+            now = time.perf_counter()
             dt = now - last_t
             tps = tokens_acc / dt if dt > 0 and tokens_acc else None
             for pm in pending:

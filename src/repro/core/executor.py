@@ -85,6 +85,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import scopes
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.core import actcache
 from repro.core import pipeline as pl
@@ -238,13 +239,15 @@ def make_fused_round(cfg: ModelConfig, tc: TrainConfig, mesh: Mesh, *,
 
             l_loc, (g_ad, g_hd) = jax.value_and_grad(
                 local_loss, argnums=(0, 1))(ad, head)
-            # head grads live only on the owner stage; psum replicates them
-            # (same semantics as differentiating a replicated P() input).
-            g_hd = jax.tree.map(lambda g: lax.psum(g, "stage"), g_hd)
-            ad2, m_ad2, v_ad2 = adamw.tree_update(
-                g_ad, m_ad, v_ad, ad, tc, lr=lr, mask=hot)
-            head2, m_hd2, v_hd2 = adamw.tree_update(
-                g_hd, m_hd, v_hd, head, tc, lr=lr)
+            with jax.named_scope(scopes.OPTIMIZER):
+                # head grads live only on the owner stage; psum replicates
+                # them (same semantics as differentiating a replicated P()
+                # input).
+                g_hd = jax.tree.map(lambda g: lax.psum(g, "stage"), g_hd)
+                ad2, m_ad2, v_ad2 = adamw.tree_update(
+                    g_ad, m_ad, v_ad, ad, tc, lr=lr, mask=hot)
+                head2, m_hd2, v_hd2 = adamw.tree_update(
+                    g_hd, m_hd, v_hd, head, tc, lr=lr)
             return (ad2, head2, m_ad2, v_ad2, m_hd2, v_hd2), (l_loc, h_B)
 
         init = (my_blocks["adapter"], shared["head"],
@@ -303,13 +306,14 @@ def make_fused_round(cfg: ModelConfig, tc: TrainConfig, mesh: Mesh, *,
 
             _, (l_loc, (g_ad, g_hd)) = lax.scan(
                 per_tenant, None, (ad, head, h_B, my_labels))  # l_loc [T]
-            g_hd = jax.tree.map(lambda g: lax.psum(g, "stage"), g_hd)
-            # stacked trees, same elementwise update: the scalar ``hot`` mask
-            # broadcasts over the leading tenant axis.
-            ad2, m_ad2, v_ad2 = adamw.tree_update(
-                g_ad, m_ad, v_ad, ad, tc, lr=lr, mask=hot)
-            head2, m_hd2, v_hd2 = adamw.tree_update(
-                g_hd, m_hd, v_hd, head, tc, lr=lr)
+            with jax.named_scope(scopes.OPTIMIZER):
+                g_hd = jax.tree.map(lambda g: lax.psum(g, "stage"), g_hd)
+                # stacked trees, same elementwise update: the scalar ``hot``
+                # mask broadcasts over the leading tenant axis.
+                ad2, m_ad2, v_ad2 = adamw.tree_update(
+                    g_ad, m_ad, v_ad, ad, tc, lr=lr, mask=hot)
+                head2, m_hd2, v_hd2 = adamw.tree_update(
+                    g_hd, m_hd, v_hd, head, tc, lr=lr)
             return (ad2, head2, m_ad2, v_ad2, m_hd2, v_hd2), (l_loc, h_B)
 
         init = (my_blocks["adapter"], shared["head"],
@@ -574,7 +578,6 @@ class RingExecutor:
                 sharding=NamedSharding(mesh, P(None, "stage")),
                 layout=self.spans)
         self._fns: Dict[Tuple[int, str], Any] = {}  # (boundary, mode) -> jit fn
-        self.trace_counts: Dict[int, int] = {}      # boundary -> #compilations
         self.mode_trace_counts: Dict[Tuple[int, str], int] = {}
         # (boundary, mode) -> {phase: scan length} — the scan lengths XLA
         # actually traced (pipeline._tick_phase reports them); the measured
@@ -592,12 +595,9 @@ class RingExecutor:
     def _fn(self, boundary: int, mode: str = "direct"):
         key = (boundary, mode)
         if key not in self._fns:
-            self.trace_counts.setdefault(boundary, 0)
 
-            def bump(b=boundary, mo=mode):
-                self.trace_counts[b] += 1
-                self.mode_trace_counts[(b, mo)] = (
-                    self.mode_trace_counts.get((b, mo), 0) + 1)
+            def bump(k=key):
+                self.mode_trace_counts[k] = self.mode_trace_counts.get(k, 0) + 1
 
             def tick_rec(phase, t, k=key):
                 self.tick_scan_lens.setdefault(k, {})[phase] = t
@@ -658,6 +658,14 @@ class RingExecutor:
     @property
     def n_executables(self) -> int:
         return len(self._fns)
+
+    @property
+    def trace_counts(self) -> Dict[int, int]:
+        """{boundary: traces over every mode} for every built boundary."""
+        out = {b: 0 for b, _ in self._fns}
+        for (b, _), n in self.mode_trace_counts.items():
+            out[b] += n
+        return out
 
     def compile_counts(self) -> Dict[str, int]:
         """{'<boundary>/<mode>': traces} — the bench's per-boundary record."""

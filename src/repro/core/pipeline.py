@@ -104,6 +104,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
+from repro import scopes
 from repro.configs.base import ModelConfig
 from repro.core.partition import (Span, frozen_stage_count, normalize_spans,
                                   span_sizes, uniform_assignment)
@@ -451,8 +452,9 @@ def gather_embeddings(cfg: ModelConfig, shared: Dict[str, Any],
     so within a round the embeddings are round-constant: the fused executor
     hoists this single ``all_gather`` out of the owner scan instead of paying
     an owner->stage0 hop per iteration.  Returns [S, M, mb, seq, D]."""
-    emb_all = jax.vmap(lambda t: tfm.embed(cfg, shared, t, pos))(my_tokens)
-    return lax.all_gather(emb_all, "stage")
+    with jax.named_scope(scopes.TRUNK):
+        emb_all = jax.vmap(lambda t: tfm.embed(cfg, shared, t, pos))(my_tokens)
+        return lax.all_gather(emb_all, "stage")
 
 
 def _ring_geometry(cfg: ModelConfig, n_stages: int, boundary: int,
@@ -479,6 +481,7 @@ def ring_phase_a(cfg: ModelConfig, *, n_stages: int, boundary: int,
     spans, F = _ring_geometry(cfg, n_stages, boundary, spans)
     fwd_perm = [(i, (i + 1) % S) for i in range(S)]
 
+    @jax.named_scope(scopes.TRUNK)
     def phase_a(owner, my_blocks, emb_g):
         s = lax.axis_index("stage")
         valid = _stage_valid(spans, s)
@@ -553,6 +556,7 @@ def ring_phase_a_packed(cfg: ModelConfig, *, n_stages: int, boundary: int,
     T = n_tenants
     fwd_perm = [(i, (i + 1) % S) for i in range(S)]
 
+    @jax.named_scope(scopes.TRUNK)
     def phase_a_packed(my_blocks, emb_g):
         s = lax.axis_index("stage")
         valid = _stage_valid(spans, s)
@@ -622,18 +626,21 @@ def ring_phase_b(cfg: ModelConfig, *, n_stages: int, boundary: int,
         pos = jnp.broadcast_to(jnp.arange(seq, dtype=jnp.int32)[None], (mb, seq))
 
         # hot 1F1B pipeline; grad => reverse ticks, stops at stage F
-        outs_B = _tick_phase(cfg, s, pos, fwd_perm, M, my_blocks, h_B, F,
-                             S_hot, valid, record)
+        with jax.named_scope(scopes.HOT):
+            outs_B = _tick_phase(cfg, s, pos, fwd_perm, M, my_blocks, h_B, F,
+                                 S_hot, valid, record)
 
         # last stage -> owner: switch over the stacked static tables
         finals = lax.switch(
             owner,
             [lambda h, t=tbl: lax.ppermute(h, "stage", t) for tbl in back_tables],
             outs_B)
-        logits = jax.vmap(lambda hh: tfm.head(cfg, shared, hh))(finals)
-        lf = logits.astype(jnp.float32)
-        lse = jax.nn.logsumexp(lf, axis=-1)
-        gold = jnp.take_along_axis(lf, my_labels[..., None], axis=-1)[..., 0]
+        with jax.named_scope(scopes.HEAD):
+            logits = jax.vmap(lambda hh: tfm.head(cfg, shared, hh))(finals)
+            lf = logits.astype(jnp.float32)
+            lse = jax.nn.logsumexp(lf, axis=-1)
+            gold = jnp.take_along_axis(lf, my_labels[..., None],
+                                       axis=-1)[..., 0]
         is_owner = (s == owner).astype(jnp.float32)
         return jnp.mean(lse - gold) * is_owner           # LOCAL (not psum'd)
 

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import scopes
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.models import transformer as tfm
 from repro.models.losses import cross_entropy, qa_span_loss
@@ -103,20 +104,22 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig, boundary: int, *,
                                       hot_adapters=tr["adapters"],
                                       head_params=tr["head"])
             ce_chunk = 512 if cfg.out_dim >= 32768 else None
-            loss, metrics = cross_entropy(logits, batch["labels"],
-                                          batch.get("mask"), chunk=ce_chunk)
+            with jax.named_scope(scopes.HEAD):
+                loss, metrics = cross_entropy(logits, batch["labels"],
+                                              batch.get("mask"), chunk=ce_chunk)
             metrics = {**metrics,
                        **{k: lax.stop_gradient(v) for k, v in aux.items()}}
             return loss, metrics
 
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             trainable)
-        tr_full = slice_to_full(params, trainable, boundary)
-        new_tr_full, new_opt = adamw.update(grads, opt_state, tr_full, tc,
-                                            boundary)
-        new_params = write_back(params, new_tr_full)
-        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                             for g in jax.tree.leaves(grads)))
+        with jax.named_scope(scopes.OPTIMIZER):
+            tr_full = slice_to_full(params, trainable, boundary)
+            new_tr_full, new_opt = adamw.update(grads, opt_state, tr_full, tc,
+                                                boundary)
+            new_params = write_back(params, new_tr_full)
+            gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                                 for g in jax.tree.leaves(grads)))
         metrics = {**metrics, "grad_norm": gnorm}
         return new_params, new_opt, metrics
 
@@ -137,14 +140,16 @@ def make_qa_train_step(cfg: ModelConfig, tc: TrainConfig, boundary: int, *,
                                     boundary=boundary, impl=impl,
                                     hot_adapters=tr["adapters"],
                                     head_params=tr["head"])
-            return qa_span_loss(logits, batch["starts"], batch["ends"])
+            with jax.named_scope(scopes.HEAD):
+                return qa_span_loss(logits, batch["starts"], batch["ends"])
 
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             trainable)
-        tr_full = slice_to_full(params, trainable, boundary)
-        new_tr_full, new_opt = adamw.update(grads, opt_state, tr_full, tc,
-                                            boundary)
-        new_params = write_back(params, new_tr_full)
+        with jax.named_scope(scopes.OPTIMIZER):
+            tr_full = slice_to_full(params, trainable, boundary)
+            new_tr_full, new_opt = adamw.update(grads, opt_state, tr_full, tc,
+                                                boundary)
+            new_params = write_back(params, new_tr_full)
         return new_params, new_opt, metrics
 
     return train_step

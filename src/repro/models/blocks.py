@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import scopes
 from repro.configs.base import ModelConfig
 from repro.core.adapter import apply_adapter
 
@@ -178,16 +179,17 @@ def _attend(q: Array, k: Array, v: Array, q_pos: Array, k_pos: Array, *,
         # the [B, H, c, Sk] fp32 score / bool mask tensors per chunk.
         chunk_fn = jax.checkpoint(chunk_fn)
 
-    if Sq <= q_chunk:
-        out = chunk_fn(qg, q_pos, k, v, k_pos)
-    else:
-        assert Sq % q_chunk == 0, (Sq, q_chunk)
-        nc = Sq // q_chunk
-        qs = qg.reshape(B, nc, q_chunk, K, G, hd).transpose(1, 0, 2, 3, 4, 5)
-        ps = q_pos.reshape(B, nc, q_chunk).transpose(1, 0, 2)
-        out = lax.map(lambda args: chunk_fn(args[0], args[1], k, v, k_pos),
-                      (qs, ps))
-        out = out.transpose(1, 0, 2, 3, 4, 5).reshape(B, Sq, K, G, hd)
+    with jax.named_scope(scopes.ATTENTION):
+        if Sq <= q_chunk:
+            out = chunk_fn(qg, q_pos, k, v, k_pos)
+        else:
+            assert Sq % q_chunk == 0, (Sq, q_chunk)
+            nc = Sq // q_chunk
+            qs = qg.reshape(B, nc, q_chunk, K, G, hd).transpose(1, 0, 2, 3, 4, 5)
+            ps = q_pos.reshape(B, nc, q_chunk).transpose(1, 0, 2)
+            out = lax.map(lambda args: chunk_fn(args[0], args[1], k, v, k_pos),
+                          (qs, ps))
+            out = out.transpose(1, 0, 2, 3, 4, 5).reshape(B, Sq, K, G, hd)
     return out.reshape(B, Sq, H, hd)
 
 

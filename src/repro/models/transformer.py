@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import scopes
 from repro.configs.base import ModelConfig
 from repro.models import kvcache
 from repro.models.blocks import BlockCtx, apply_block, norm
@@ -165,36 +166,38 @@ def forward(params, tokens: Array, cfg: ModelConfig, *,
                         act_spec=act_spec)
 
     pos = jnp.broadcast_to(jnp.arange(nm + S, dtype=jnp.int32)[None], (B, nm + S))
-    h = embed(cfg, params, tokens, pos[:, nm:] if nm else pos)
-    if nm:
-        meta = jnp.broadcast_to(params["meta"][None].astype(h.dtype),
-                                (B, nm, cfg.d_model))
-        h = jnp.concatenate([meta, h], axis=1)
-
     ctx = BlockCtx(cfg=cfg, mode="seq", positions=pos, causal=True, memory=memory,
                    impl=impl, q_chunk=pick_chunk(nm + S), remat=remat,
                    act_spec=act_spec, moe_groups=moe_groups)
 
     aux = _ZERO_AUX()
     blocks = params["blocks"]
-    if boundary > 0:
-        frozen = tuple(_tree_slice(e, 0, boundary) for e in blocks)
-        frozen = lax.stop_gradient(frozen)
-        h, aux, _ = _run_repeats(cfg, frozen, h, aux, ctx)
-        # === RingAda early-stop point: no gradients flow below this line ===
-        h = lax.stop_gradient(h)
-        aux = jax.tree.map(lax.stop_gradient, aux)
+    with jax.named_scope(scopes.TRUNK):
+        h = embed(cfg, params, tokens, pos[:, nm:] if nm else pos)
+        if nm:
+            meta = jnp.broadcast_to(params["meta"][None].astype(h.dtype),
+                                    (B, nm, cfg.d_model))
+            h = jnp.concatenate([meta, h], axis=1)
+        if boundary > 0:
+            frozen = tuple(_tree_slice(e, 0, boundary) for e in blocks)
+            frozen = lax.stop_gradient(frozen)
+            h, aux, _ = _run_repeats(cfg, frozen, h, aux, ctx)
+            # === RingAda early-stop point: no gradients flow below this line ===
+            h = lax.stop_gradient(h)
+            aux = jax.tree.map(lax.stop_gradient, aux)
     if boundary < cfg.repeats:
-        hot = tuple(_tree_slice(e, boundary, cfg.repeats) for e in blocks)
-        if hot_adapters is not None:
-            hot = tuple({**e, "adapter": ha}
-                        for e, ha in zip(hot, hot_adapters))
-        h, aux, _ = _run_repeats(cfg, hot, h, aux, ctx)
+        with jax.named_scope(scopes.HOT):
+            hot = tuple(_tree_slice(e, boundary, cfg.repeats) for e in blocks)
+            if hot_adapters is not None:
+                hot = tuple({**e, "adapter": ha}
+                            for e, ha in zip(hot, hot_adapters))
+            h, aux, _ = _run_repeats(cfg, hot, h, aux, ctx)
 
     if nm:
         h = h[:, nm:]
     hp = {**params, "head": head_params} if head_params is not None else params
-    logits = head(cfg, hp, h)
+    with jax.named_scope(scopes.HEAD):
+        logits = head(cfg, hp, h)
     return logits, aux
 
 
