@@ -34,11 +34,11 @@ def rwkv_scan(r, k, v, lw, u, state0):
     return _rs.rwkv_scan(r, k, v, lw, u, state0, interpret=_interpret())
 
 
-@partial(jax.jit, static_argnames=("group", "causal", "window"))
-def flash_attention(q, k, v, *, group: int = 1, causal: bool = True,
-                    window=None):
-    return _fa.flash_attention(q, k, v, group=group, causal=causal,
-                               window=window, interpret=_interpret())
+@partial(jax.jit, static_argnames=("causal", "window"))
+def flash_attention(q, k, v, *, causal: bool = True, window=None):
+    """q [B, H, Sq, hd]; k, v [B, K, Sk, hd] (GQA when K < H)."""
+    return _fa.flash_attention(q, k, v, causal=causal, window=window,
+                               interpret=_interpret())
 
 
 @jax.jit

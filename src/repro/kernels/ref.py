@@ -44,9 +44,11 @@ def rwkv_scan(r: Array, k: Array, v: Array, lw: Array, u: Array,
 
 def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
                     window: int | None = None) -> Array:
-    """q [N, Sq, hd]; k,v [N, Sk, hd] (kv heads pre-aligned). fp32 softmax."""
-    Sq, Sk = q.shape[1], k.shape[1]
-    s = jnp.einsum("nqh,nkh->nqk", q, k,
+    """q [B, H, Sq, hd]; k,v [B, K, Sk, hd], H a multiple of K. fp32 softmax."""
+    Sq, Sk = q.shape[2], k.shape[2]
+    group = q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
+    s = jnp.einsum("bnqh,bnkh->bnqk", q, k,
                    preferred_element_type=jnp.float32)
     s = s / jnp.sqrt(jnp.float32(q.shape[-1]))
     qi = jnp.arange(Sq)[:, None] + (Sk - Sq)    # align last query with last key
@@ -56,10 +58,10 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
         m &= ki <= qi
     if window is not None:
         m &= (qi - ki) < window
-    s = jnp.where(m[None], s, -1e30)
+    s = jnp.where(m, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    p = jnp.where(m[None], p, 0.0)
-    return jnp.einsum("nqk,nkh->nqh", p.astype(v.dtype), v)
+    p = jnp.where(m, p, 0.0)
+    return jnp.einsum("bnqk,bnkh->bnqh", p.astype(v.dtype), v)
 
 
 def mamba_scan(log_a: Array, b: Array, c: Array):

@@ -29,6 +29,7 @@ from jax import lax
 from repro import scopes
 from repro.configs.base import ModelConfig
 from repro.core.adapter import apply_adapter
+from repro.kernels.flash_attention import flash_attention
 
 Array = jax.Array
 
@@ -61,6 +62,9 @@ class BlockCtx:
     remat: bool = False             # per-block activation checkpointing
     act_spec: Any = None            # PartitionSpec pinned on the residual stream
     moe_groups: int = 1             # GShard group-local dispatch groups
+    # no tangent reaches these blocks (the frozen trunk under stop_gradient):
+    # set by transformer.forward only, never a user's choice
+    primal_only: bool = False
 
 
 # ---------------------------------------------------------------------------
@@ -111,14 +115,16 @@ def ffn(cfg: ModelConfig, p: Dict[str, Array], x: Array) -> Array:
 # ---------------------------------------------------------------------------
 
 
-def rope(x: Array, positions: Array, theta: float) -> Array:
-    """x: [B, S, H, hd]; positions: [B, S]."""
+def rope(x: Array, positions: Array, theta: float, *,
+         heads_first: bool = False) -> Array:
+    """x: [B, S, H, hd] ([B, H, S, hd] if ``heads_first``); positions: [B, S]."""
     hd = x.shape[-1]
     half = hd // 2
     freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
     ang = positions[..., None].astype(jnp.float32) * freqs          # [B, S, half]
-    cos = jnp.cos(ang)[:, :, None, :]
-    sin = jnp.sin(ang)[:, :, None, :]
+    at = (lambda a: a[:, None]) if heads_first else (lambda a: a[:, :, None])
+    cos = at(jnp.cos(ang))
+    sin = at(jnp.sin(ang))
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -193,12 +199,66 @@ def _attend(q: Array, k: Array, v: Array, q_pos: Array, k_pos: Array, *,
     return out.reshape(B, Sq, H, hd)
 
 
+# query and key block of the flash kernel (see kernels/flash_attention.py)
+FLASH_BLOCK = 512
+
+
+def _flash(q: Array, k: Array, v: Array, *, window: Optional[int],
+           interpret: bool = False) -> Array:
+    """Causal self-attention through the flash kernel. q [B,H,S,hd];
+    k,v [B,K,S,hd] at positions 0..S-1. Returns [B, H, S, hd]."""
+    blk = min(FLASH_BLOCK, q.shape[2])
+    return flash_attention(q, k, v, causal=True, window=window, block_q=blk,
+                           block_k=blk, interpret=interpret)
+
+
+def _attend_primal(q: Array, k: Array, v: Array, positions: Array, *,
+                   window: Optional[int], q_chunk: int) -> Array:
+    """Causal self-attention that is never differentiated, heads first
+    ([B, H, S, hd]): the flash kernel where the program is lowered for a
+    TPU, ``_attend`` elsewhere."""
+    def plain(q, k, v, positions):
+        seq = lambda t: t.transpose(0, 2, 1, 3)
+        return seq(_attend(seq(q), seq(k), seq(v), positions, positions,
+                           causal=True, window=window, n_sink=0,
+                           q_chunk=q_chunk))
+
+    def kernel(q, k, v, positions):
+        with jax.named_scope(scopes.ATTENTION):
+            return _flash(q, k, v, window=window)
+
+    return lax.platform_dependent(q, k, v, positions, tpu=kernel,
+                                  default=plain)
+
+
+def _attention_primal(cfg: ModelConfig, p: Dict[str, Array], x: Array,
+                      ctx: BlockCtx) -> Array:
+    """``attention`` of a block that no tangent reaches, without a cache: the
+    projections emit the kernel's [B, H, S, hd] layout directly."""
+    proj = lambda w: jnp.einsum("bsd,dhk->bhsk", x, w)
+    q, kk, vv = proj(p["wq"]), proj(p["wk"]), proj(p["wv"])
+    if "bq" in p:
+        q, kk, vv = (t + b[:, None] for t, b in
+                     ((q, p["bq"]), (kk, p["bk"]), (vv, p["bv"])))
+    if cfg.rope:
+        q = rope(q, ctx.positions, cfg.rope_theta, heads_first=True)
+        kk = rope(kk, ctx.positions, cfg.rope_theta, heads_first=True)
+    out = _attend_primal(q, kk, vv, ctx.positions, window=cfg.sliding_window,
+                         q_chunk=ctx.q_chunk)
+    return jnp.einsum("bhsk,hkd->bsd", out.astype(x.dtype), p["wo"])
+
+
 def attention(cfg: ModelConfig, p: Dict[str, Array], x: Array, ctx: BlockCtx,
               cache: Optional[Dict[str, Array]] = None,
               ) -> Tuple[Array, Optional[Dict[str, Array]]]:
     """Self-attention with optional KV cache (decode) and sliding window."""
     B, S, D = x.shape
     H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    n_sink = 128 if any(kind == "hymba" for kind, _ in cfg.pattern) else 0
+    if (ctx.primal_only and ctx.mode == "seq" and cache is None and ctx.causal
+            and n_sink == 0 and ctx.act_spec is None
+            and S % min(FLASH_BLOCK, S) == 0):
+        return _attention_primal(cfg, p, x, ctx), None
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     kk = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     vv = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
@@ -208,7 +268,6 @@ def attention(cfg: ModelConfig, p: Dict[str, Array], x: Array, ctx: BlockCtx,
         q = rope(q, ctx.positions, cfg.rope_theta)
         kk = rope(kk, ctx.positions, cfg.rope_theta)
 
-    n_sink = 128 if any(kind == "hymba" for kind, _ in cfg.pattern) else 0
     new_cache = None
 
     def _quant(t):

@@ -181,7 +181,8 @@ def forward(params, tokens: Array, cfg: ModelConfig, *,
         if boundary > 0:
             frozen = tuple(_tree_slice(e, 0, boundary) for e in blocks)
             frozen = lax.stop_gradient(frozen)
-            h, aux, _ = _run_repeats(cfg, frozen, h, aux, ctx)
+            h, aux, _ = _run_repeats(cfg, frozen, h, aux,
+                                     dataclasses.replace(ctx, primal_only=True))
             # === RingAda early-stop point: no gradients flow below this line ===
             h = lax.stop_gradient(h)
             aux = jax.tree.map(lax.stop_gradient, aux)

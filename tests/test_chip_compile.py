@@ -79,7 +79,7 @@ def test_kernel_compiles_for_v5e(kernel, one_chip):
         shapes = [(4096, 2560), (2560, 64), (64, 2560)]
     else:
         fn = lambda q, k, v: fa.flash_attention(q, k, v, interpret=False)
-        shapes = [(32, 2048, 80)] * 3
+        shapes = [(1, 32, 2048, 80)] * 3
     args = [jax.ShapeDtypeStruct(s, bf16, sharding=one_chip) for s in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -118,6 +118,49 @@ def test_fused_ring_round_compiles_for_v5e_2x2(topo):
         mem.argument_size_in_bytes, per_chip)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_V5E
     assert "collective-permute" in compiled.as_text()
+
+
+def test_pjit_trunk_attention_is_the_flash_kernel(one_chip):
+    """Lowered for the chip, the pjit step at boundary 1 of 2 runs the
+    frozen block's attention as the named Pallas kernel, inside the trunk's
+    scope and under the attention tag (at 2 x 1024 tokens)."""
+    cfg = _cut(2)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    params = jax.eval_shape(
+        lambda k: prm.materialize(prm.param_defs(cfg), k, cfg.dtype), key)
+    opt = jax.eval_shape(lambda p: adamw.init(training.full_trainable(p)),
+                         params)
+    batch = {k: jax.ShapeDtypeStruct((2, 1024), jnp.int32, sharding=one_chip)
+             for k in ("tokens", "labels")}
+    step = jax.jit(training.make_train_step(cfg, TrainConfig(), 1))
+    text = step.lower(_sds(params, one_chip), _sds(opt, one_chip),
+                      batch).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert calls and all(fa.NAME in ln for ln in calls), calls
+    for ln in calls:
+        assert "ringada.trunk" in ln and "ringada.attention" in ln, ln
+
+
+def test_fused_round_one_chip_has_no_kernel(topo):
+    """The fused ring at S = 1, all blocks hot: every attention it runs is
+    differentiated, so its round holds no Pallas call."""
+    cfg = _cut(2)
+    mesh = Mesh(np.array(topo.devices[:1]), ("stage",),
+                axis_types=(AxisType.Auto,))
+    stage, rep = pl.stage_shardings(mesh)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    blocks, shared = jax.eval_shape(
+        lambda k: pl.stage_stack(prm.materialize(prm.param_defs(cfg), k,
+                                                 cfg.dtype), cfg, 1), key)
+    opt = jax.eval_shape(ring_opt_init, blocks, shared)
+    opt = jax.tree.map(lambda s, sub: _sds(sub, NamedSharding(mesh, s)),
+                       ring_opt_specs(), opt)
+    tokens = jax.ShapeDtypeStruct((1, 1, 1, 1024), jnp.int32, sharding=stage)
+    tc = TrainConfig(n_microbatches=1, batch_size=1, seq_len=1024)
+    fn = make_fused_round(cfg, tc, mesh, n_stages=1, boundary=0, n_micro=1)
+    args = (_sds(blocks, stage), _sds(shared, rep), opt, tokens, tokens)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" not in text
 
 
 def test_activation_memory_shrinks_with_boundary(one_chip):

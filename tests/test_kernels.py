@@ -74,20 +74,55 @@ def test_rwkv_scan_state_chaining():
 ])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_flash_attention_sweep(Sq, Sk, hd, group, window, dtype):
-    Nk = 2
+    B, Nk = 2, 2
     Nq = Nk * group
-    q = jax.random.normal(jax.random.key(0), (Nq, Sq, hd), dtype)
-    k = jax.random.normal(jax.random.key(1), (Nk, Sk, hd), dtype)
-    v = jax.random.normal(jax.random.key(2), (Nk, Sk, hd), dtype)
-    got = fak.flash_attention(q, k, v, group=group, window=window,
-                              block_q=64, block_k=64, interpret=True)
-    want = jnp.stack([
-        ref.flash_attention(q[i:i + 1], k[i // group:i // group + 1],
-                            v[i // group:i // group + 1], window=window)[0]
-        for i in range(Nq)])
+    q = jax.random.normal(jax.random.key(0), (B, Nq, Sq, hd), dtype)
+    k = jax.random.normal(jax.random.key(1), (B, Nk, Sk, hd), dtype)
+    v = jax.random.normal(jax.random.key(2), (B, Nk, Sk, hd), dtype)
+    got = fak.flash_attention(q, k, v, window=window, block_q=64, block_k=64,
+                              interpret=True)
+    want = ref.flash_attention(q, k, v, window=window)
     atol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=atol)
+
+
+@pytest.mark.parametrize("S,hd,group,window,block", [
+    (256, 80, 1, None, 64),       # causal: 4 key blocks a row, 6 skipped
+    (256, 128, 1, None, 64),      # the MXU's width next to the model's 80
+    (256, 80, 2, None, 64),       # GQA: two query heads per K/V head
+    (256, 80, 1, 96, 64),         # a window: blocks below it skipped too
+    (512, 80, 1, None, 128),
+])
+def test_flash_attention_matches_attend(S, hd, group, window, block):
+    """The kernel against the model's own ``_attend`` on bf16 inputs, each
+    side against float32 attention on the same inputs.
+
+    Each side rounds twice to bf16 (8 significant bits, so at most 2^-8
+    relative each): the probabilities that meet v, which moves an output by
+    at most 2^-8 max|v|, and the output itself, at most 2^-8 max|v| again.
+    So each lies within 2^-7 max|v| of float32, and the two within 2^-6."""
+    from repro.models.blocks import _attend
+    B, K = 2, 2
+    H = K * group
+    keys = jax.random.split(jax.random.key(3), 3)
+    q = jax.random.normal(keys[0], (B, S, H, hd), jnp.bfloat16)
+    k = jax.random.normal(keys[1], (B, S, K, hd), jnp.bfloat16)
+    v = jax.random.normal(keys[2], (B, S, K, hd), jnp.bfloat16)
+    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+    heads = lambda t: t.transpose(0, 2, 1, 3)
+    got = heads(fak.flash_attention(heads(q), heads(k), heads(v),
+                                    window=window, block_q=block,
+                                    block_k=block, interpret=True))
+    plain = _attend(q, k, v, pos, pos, causal=True, window=window, n_sink=0,
+                    q_chunk=S // 2)
+    f32 = _attend(*(t.astype(jnp.float32) for t in (q, k, v)), pos, pos,
+                  causal=True, window=window, n_sink=0, q_chunk=S // 2)
+    vmax = float(jnp.max(jnp.abs(v.astype(jnp.float32))))
+    got, plain, f32 = (np.asarray(t, np.float32) for t in (got, plain, f32))
+    assert np.abs(got - f32).max() <= 2.0 ** -7 * vmax
+    assert np.abs(plain - f32).max() <= 2.0 ** -7 * vmax
+    assert np.abs(got - plain).max() <= 2.0 ** -6 * vmax
 
 
 def test_ops_wrappers_jit():
